@@ -4,21 +4,6 @@ compute path (xPU analogue):    flash_attn.py, moe_gemm.py
 bandwidth path (Logic-PIM):     decode_attn.py (dense + paged), moe_gemv.py
 wrappers / oracles:             ops.py, ref.py
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# --- JAX version compat -----------------------------------------------------
-# The TPU compiler-params dataclass was renamed across JAX releases
-# (TPUCompilerParams <-> CompilerParams). Every kernel module builds its
-# compiler params through this shim so either spelling of JAX works.
-_COMPILER_PARAMS_CLS = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
-
-
-def tpu_compiler_params(**kwargs):
-    """Construct pltpu compiler params under whichever name this JAX has."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
-
 import jax.numpy as jnp
 
 
@@ -46,4 +31,4 @@ from repro.kernels.ops import (decode_attention, flash_attention, moe_gemm,
 
 __all__ = ["decode_attention", "flash_attention", "int8_quantize",
            "moe_gemm", "moe_gemv", "paged_decode_attention",
-           "ragged_moe_gemm", "tpu_compiler_params"]
+           "ragged_moe_gemm"]

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -120,7 +119,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((qpk, q_block), jnp.float32),       # m
             pltpu.VMEM((qpk, q_block), jnp.float32),       # l
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _moe_gemm_kernel(x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref, *,
                      nf: int):
@@ -62,7 +60,7 @@ def _moe_gemm_kernel(x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref, *,
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def moe_gemm_kernel(w, x, *, c_block: int = 256, f_block: int = 512,
+def moe_gemm_kernel(w, x, *, c_block: int = 256, f_block: int = 256,
                     interpret: bool = False):
     """w: dict wi_gate/wi_up (E, d, f), wo (E, f, d); x: (E, C, d).
     C % c_block == 0 and f % f_block == 0 (ops.py pads). -> (E, C, d)."""
@@ -87,7 +85,7 @@ def moe_gemm_kernel(w, x, *, c_block: int = 256, f_block: int = 512,
         out_specs=pl.BlockSpec((1, c_block, d), lambda e, ci, fi: (e, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((c_block, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w["wi_gate"], w["wi_up"], w["wo"])
@@ -139,7 +137,7 @@ def _live_block_operands(counts, c_block: int, cap: int):
 
 
 def ragged_moe_gemm_kernel(w, x, counts, *, c_block: int = 256,
-                           f_block: int = 512,
+                           f_block: int = 256,
                            blocks_bound: int | None = None,
                            interpret: bool = False):
     """w: dict wi_gate/wi_up (E, d, f), wo (E, f, d); x: (E, C, d) slot
@@ -204,7 +202,7 @@ def ragged_moe_gemm_kernel(w, x, counts, *, c_block: int = 256,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(nb, lle, x, w["wi_gate"], w["wi_up"], w["wo"])

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _moe_gemv_kernel(x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref, *,
                      nf: int):
@@ -77,7 +75,7 @@ def moe_gemv_kernel(w, x, *, f_block: int = 256, interpret: bool = False):
         out_specs=pl.BlockSpec((1, Cc, d), lambda e, fi: (e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Ec, Cc, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((Cc, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w["wi_gate"], w["wi_up"], w["wo"])
@@ -160,7 +158,7 @@ def ragged_moe_gemv_kernel(w, x, counts, *, f_block: int = 256,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Ec, Cc, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(counts, lle, x, w["wi_gate"], w["wi_up"], w["wo"])

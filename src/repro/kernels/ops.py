@@ -3,9 +3,12 @@
 These adapt model-layer layouts to kernel layouts (GQA head grouping,
 block padding) and select the execution mode:
 
-  * on TPU backends: the Pallas kernels proper;
-  * on CPU (this container): ``interpret=True`` executes the kernel bodies in
-    Python for correctness validation against ``ref.py``.
+  * on the TPU backend: the Pallas kernels proper;
+  * on the CPU backend (tests, ``JAX_PLATFORMS=cpu``): ``interpret=True``
+    executes the kernel bodies in Python for correctness validation against
+    ``ref.py``;
+  * on any other backend: an error — a run that meant to use the chip never
+    carries on silently through interpret mode.
 
 The XLA fallbacks in models/attention.py remain the lowering used by the
 dry-run (Pallas doesn't lower on the CPU backend); kernels are the TPU
@@ -19,9 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.decode_attn import (chunked_prefill_attention_kernel,
-                                       decode_attention_kernel,
-                                       paged_decode_attention_kernel)
+from repro.kernels.decode_attn import (decode_attention_kernel,
+                                       paged_attention_kernel)
 from repro.kernels.flash_attn import flash_attention_kernel
 from repro.kernels.moe_gemm import moe_gemm_kernel, ragged_moe_gemm_kernel
 from repro.kernels.moe_gemv import moe_gemv_kernel, ragged_moe_gemv_kernel
@@ -29,7 +31,14 @@ from repro.kernels.ssd_decode import ssd_decode_kernel
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or in interpret mode on the "
+        f"CPU backend; found backend {backend!r}")
 
 
 def _pad_to(x, multiple: int, axis: int):
@@ -109,14 +118,14 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, block_tables, *,
     qpk = H // KV
     interpret = _interpret_default() if interpret is None else interpret
     qg = q.reshape(B, KV, qpk, hd)
-    out = paged_decode_attention_kernel(qg, k_pages, v_pages,
-                                        lengths.astype(jnp.int32),
-                                        block_tables,
-                                        k_scale_pages=k_scales,
-                                        v_scale_pages=v_scales,
-                                        window=window, softcap=softcap,
-                                        pages_bound=pages_bound,
-                                        interpret=interpret)
+    lengths = lengths.astype(jnp.int32)
+    # a decode row is a one-position chunk at position length - 1
+    out = paged_attention_kernel(qg, k_pages, v_pages, lengths, lengths - 1,
+                                 block_tables, k_scale_pages=k_scales,
+                                 v_scale_pages=v_scales, qpk=qpk,
+                                 window=window, softcap=softcap,
+                                 pages_bound=pages_bound,
+                                 interpret=interpret)
     return out.reshape(B, 1, H, hd)
 
 
@@ -141,11 +150,10 @@ def chunked_prefill_attention(q, k_pages, v_pages, totals, starts,
     # (B, KV, Sc*qpk, hd), heads innermost so row r = chunk position r // qpk
     qg = q.reshape(B, Sc, KV, qpk, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KV, Sc * qpk, hd)
-    out = chunked_prefill_attention_kernel(
-        qg, k_pages, v_pages, totals.astype(jnp.int32),
-        starts.astype(jnp.int32), block_tables, k_scale_pages=k_scales,
-        v_scale_pages=v_scales, qpk=qpk, softcap=softcap,
-        pages_bound=pages_bound, interpret=interpret)
+    out = paged_attention_kernel(
+        qg, k_pages, v_pages, totals, starts, block_tables,
+        k_scale_pages=k_scales, v_scale_pages=v_scales, qpk=qpk,
+        softcap=softcap, pages_bound=pages_bound, interpret=interpret)
     out = out.reshape(B, KV, Sc, qpk, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, Sc, H, hd)
 
@@ -154,7 +162,7 @@ def chunked_prefill_attention(q, k_pages, v_pages, totals, starts,
 # MoE paths
 # ---------------------------------------------------------------------------
 
-def moe_gemm(w, x, *, c_block: int = 256, f_block: int = 512,
+def moe_gemm(w, x, *, c_block: int = 256, f_block: int = 256,
              interpret: bool | None = None):
     """Hot-expert grouped GEMM. x: (E, C, d) -> (E, C, d)."""
     interpret = _interpret_default() if interpret is None else interpret
@@ -172,7 +180,7 @@ def moe_gemm(w, x, *, c_block: int = 256, f_block: int = 512,
     return out[:, :C]
 
 
-def ragged_moe_gemm(w, x, counts, *, c_block: int = 256, f_block: int = 512,
+def ragged_moe_gemm(w, x, counts, *, c_block: int = 256, f_block: int = 256,
                     blocks_bound: int | None = None,
                     interpret: bool | None = None):
     """Count-aware hot-expert grouped GEMM. x: (E, C, d) slot buffers (live

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _ssd_decode_kernel(state_ref, x_ref, dt_ref, a_log_ref, b_ref, c_ref,
                        d_ref, y_ref, new_state_ref):
@@ -71,7 +69,7 @@ def ssd_decode_kernel(state, x, dt, a_log, b, c, d, *, h_block: int = 8,
             jax.ShapeDtypeStruct((B, H, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(state, x, dt, a_log, b, c, d)

@@ -8,8 +8,12 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both --out artifacts/dryrun
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ must precede every jax-importing import (jax locks device count on init)
+# must precede every jax-importing import (jax locks the device count on
+# init): 512 virtual CPU devices, added to any flags the caller set, and the
+# CPU platform pinned so a machine with an accelerator never starts it here
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

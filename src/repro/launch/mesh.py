@@ -10,14 +10,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` /
-    ``jax.sharding.AxisType`` only exist in newer releases, and Auto is
-    the default there anyway."""
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    if axis_type_cls is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type_cls.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (GSPMD propagates shardings
+    from the logical constraints in ``sharding/rules.py``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,8 +3,10 @@
   PYTHONPATH=src python -m repro.launch.serve --arch tiny-moe --requests 16
   PYTHONPATH=src python -m repro.launch.serve --arch olmoe-1b-7b --reduced
 
-Runs the real ServingEngine on CPU at reduced width; reports T2FT/TBT/E2E
-and the per-stage dispatch decisions (bandwidth-path FLOP fraction, k_cold).
+Runs the real ServingEngine; reports T2FT/TBT/E2E and the per-stage
+dispatch decisions (bandwidth-path FLOP fraction, k_cold). On a TPU the
+``--kernels`` path runs the compiled Pallas kernels; with
+``JAX_PLATFORMS=cpu`` it runs them in interpret mode.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import resolve_config
 from repro.models.model import init_model
 from repro.serving.engine import ServingEngine
@@ -28,24 +31,15 @@ from repro.serving.router import ROUTER_POLICIES
 def profiled(log_dir):
     """Wrap the serving loop in ``jax.profiler.trace`` (the levanter
     Performance-Guide recipe): profile exactly the loop, nothing else, and
-    print where the trace landed. Degrades to unprofiled with a warning if
-    the profiler backend is unavailable in this build."""
+    print where the trace landed. A profiler that fails to start fails the
+    run: asking for a trace and getting none is an error."""
     if not log_dir:
         yield
         return
-    try:
-        ctx = jax.profiler.trace(log_dir)
-        ctx.__enter__()
-    except Exception as e:                               # pragma: no cover
-        print(f"[serve] profiler unavailable ({e}); running unprofiled")
+    with jax.profiler.trace(log_dir):
         yield
-        return
-    try:
-        yield
-    finally:
-        ctx.__exit__(None, None, None)
-        print(f"[serve] profiler trace written under {log_dir} "
-              f"(view: tensorboard --logdir {log_dir})")
+    print(f"[serve] profiler trace written under {log_dir} "
+          f"(view: tensorboard --logdir {log_dir})")
 
 
 def run_fleet(args, make_engine, injector, reqs) -> int:
@@ -194,14 +188,17 @@ def main(argv=None) -> int:
                         "TensorBoard or Perfetto)")
     p.add_argument("--no-duplex", action="store_true")
     p.add_argument("--kernels", action="store_true",
-                   help="lower through the Pallas kernels (interpret mode "
-                        "on CPU); with duplex this enables the ragged "
-                        "count-threaded MoE path")
+                   help="lower attention and MoE through the Pallas "
+                        "kernels: compiled on a TPU, interpret mode under "
+                        "JAX_PLATFORMS=cpu (slow; for correctness), an "
+                        "error on any other backend; with duplex this "
+                        "enables the ragged count-threaded MoE path")
     p.add_argument("--no-moe-ragged", action="store_true",
                    help="with --kernels: keep the capacity-padded MoE "
                         "kernels instead of the ragged ones")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = resolve_config(args.arch, args.reduced)
     if cfg.is_encoder_decoder:

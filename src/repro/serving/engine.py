@@ -579,6 +579,13 @@ class ServingEngine:
             moe_ragged=self.moe_ragged, moe_c_block=c_block,
             use_kernels=self.use_kernels)
 
+    def execution_plan_for(self, n_tokens: int,
+                           k_cold: int = 0) -> ExecutionPlan:
+        """The plan a stage of ``n_tokens`` (bucketed, padding included)
+        tokens at ``k_cold`` is traced under — for checking the kernel path
+        against ``dataclasses.replace(plan, use_kernels=False)``."""
+        return self._moe_plan(k_cold, *self._moe_caps(n_tokens, k_cold))
+
     def _decode_fn(self, k_cold: int, c_hot: int, c_cold: int, c_block: int):
         key = (k_cold, c_hot, c_cold)
         if key not in self._decode_fns:

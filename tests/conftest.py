@@ -1,53 +1,7 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see 1 device (the dry-run sets its own 512-device flag in its own process).
-
-Also installs an optional-import shim for ``hypothesis``: this container has
-no network access, and a hard import error in a test module would kill the
-whole module's collection. With the shim, only the property-based tests are
-skipped when hypothesis is absent; the plain pytest tests in the same module
-still run.
 """
-import sys
-import types
-
 import pytest
-
-try:  # pragma: no cover - exercised only when hypothesis is installed
-    import hypothesis  # noqa: F401
-except ImportError:
-    class _AnyStrategy:
-        """Stands in for any strategy object/callable at decoration time."""
-
-        def __call__(self, *args, **kwargs):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def _skipped():
-                pytest.skip("hypothesis not installed (offline container)")
-
-            _skipped.__name__ = fn.__name__
-            _skipped.__doc__ = fn.__doc__
-            return _skipped
-
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        return lambda fn: fn
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = lambda *a, **k: True
-    _hyp.HealthCheck = _AnyStrategy()
-    _strategies = types.ModuleType("hypothesis.strategies")
-    _strategies.__getattr__ = lambda name: _AnyStrategy()
-    _hyp.strategies = _strategies
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _strategies
 
 import jax
 import jax.numpy as jnp

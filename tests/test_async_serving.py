@@ -244,10 +244,12 @@ def test_fleet_async_steps(async_setup):
     assert outs[False] == outs[True]    # replica-level parity
 
 
-def test_serve_cli_async_profile(tmp_path):
+def test_serve_cli_async_profile(tmp_path, monkeypatch):
     """`serve --async --profile DIR` exits 0 and writes a trace; the
     printed stats include the async pipeline counters."""
     from repro.launch.serve import main
+    # keep main()'s compile cache out of the checkout for this test process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     prof = tmp_path / "trace"
     rc = main(["--arch", "tiny-dense", "--no-duplex", "--async",
                "--requests", "3", "--l-in", "8", "--l-out", "3",

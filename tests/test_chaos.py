@@ -6,9 +6,7 @@ step errors, every request still finishes with greedy-token parity against
 the fault-free run, ``KVManager.audit()`` is clean after every stage, and
 the pool drains to fully-free. The property-based test fuzzes random
 submit/step/cancel sequences across the layout × sharing × preemption
-matrix through the same helper a deterministic twin drives (so the logic
-runs even where hypothesis is absent — conftest's shim skips only the
-fuzzing wrapper).
+matrix through the same helper a deterministic twin drives.
 """
 import jax
 import numpy as np

@@ -108,3 +108,46 @@ def test_dryrun_subprocess_one_cell(tmp_path):
     assert rec["mesh_info"]["num_devices"] == 256
     # this process still sees its own device world
     assert len(jax.devices()) < 256
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import use_compile_cache
+print(use_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if {compile}:
+    jax.jit(lambda x: jnp.sin(x) * 2 + 1)(jnp.ones(8)).block_until_ready()
+"""
+
+
+def _cache_probe(env_dir, compile_):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=os.path.join(REPO, "src"), JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=compile_)],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO)
+    assert r.returncode == 0, r.stdout + r.stderr
+    return r.stdout.split()
+
+
+def test_compile_cache_honours_env_dir(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own reading of it stands, and
+    compiled programs land there."""
+    cache = tmp_path / "cache"
+    used, configured = _cache_probe(cache, True)
+    assert used == configured == str(cache)
+    assert any(cache.iterdir())
+
+
+def test_compile_cache_defaults_to_checkout_dir():
+    """Unset: the fixed, git-ignored directory at the checkout's root."""
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR
+    used, configured = _cache_probe(None, False)
+    assert used == configured == str(CHECKOUT_CACHE_DIR)
+    assert str(CHECKOUT_CACHE_DIR.parent) == os.path.realpath(REPO)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
