@@ -20,6 +20,7 @@ import numpy as np
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import resolve_config
 from repro.models.model import init_model
+from repro.serving import tracing
 from repro.serving.engine import ServingEngine
 from repro.serving.faults import FaultInjector
 from repro.serving.fleet import Fleet, FleetStalledError
@@ -40,6 +41,29 @@ def profiled(log_dir):
         yield
     print(f"[serve] profiler trace written under {log_dir} "
           f"(view: tensorboard --logdir {log_dir})")
+
+
+def span_summary(log: tracing.SpanLog) -> str:
+    """The engine's span log in one line: mean ms a stage of each
+    ``engine.*`` phase over the stages the log holds (an ``engine.step``
+    with a stage index, or an ``engine.turn``, which commits one stage),
+    the mean queue wait per admission, and the records it dropped."""
+    recs = log.spans()
+    n = sum(1 for s in recs if s.name == "engine.turn"
+            or s.name == "engine.step" and s.stage is not None)
+    tot = log.totals()
+    line = (f"[serve] engine spans over {n} stages, mean ms/stage: "
+            + " ".join(f"{name}={sec * 1e3 / max(n, 1):.3f}"
+                       for name, (_, sec) in sorted(tot.items())
+                       if name != "engine.queue"))
+    if "engine.queue" in tot:
+        k, sec = tot["engine.queue"]
+        rids = {s.rid for s in recs if s.name == "engine.queue"}
+        line += (f"; engine.queue mean {sec * 1e3 / k:.3f} ms over {k} "
+                 f"admissions of {len(rids)} requests")
+    if log.dropped:
+        line += f"; {log.dropped} older records dropped"
+    return line
 
 
 def run_fleet(args, make_engine, injector, reqs) -> int:
@@ -311,6 +335,7 @@ def main(argv=None) -> int:
     if preemption != "none" or args.oversubscribe is not None:
         print(f"[serve] preemption({preemption}): {eng.preemptions} "
               f"evictions, peak concurrent batch={eng.peak_active}")
+    print(span_summary(tracing.LOG))
     st2 = eng.stats()
     if args.async_loop:
         gap_ms = st2["host_gap_s"] * 1e3 / max(st2["gap_stages"], 1)

@@ -75,6 +75,14 @@ cancel, an eviction, an expiry) invalidates the speculative plan and the
 engine re-plans from real state — speculation affects only the overlap,
 never the tokens. ``submit``/``cancel``/``stats`` are lock-guarded so a
 fleet poller (or a client thread) is safe against the loop.
+
+Spans (``serving/tracing.py``, on the profiler's clock): ``engine.step``
+(with the stage index) and ``engine.turn`` around the two loops' units;
+inside them ``engine.plan`` (children ``.maintain``, ``.admit``, ``.draft``,
+``.schedule``, ``.duplex``), ``engine.dispatch`` (``engine.dispatch.inputs``,
+``engine.launch``), ``engine.sync``, ``engine.commit`` and
+``engine.account``; and one ``engine.queue`` per admission, from the
+request's entry into the queue to its KV slot.
 """
 from __future__ import annotations
 
@@ -94,6 +102,7 @@ from repro.core.dispatch import plan_stage as core_plan_stage
 from repro.core.execution import ExecutionPlan, execution_plan
 from repro.core.partition import DuplexPlanner, build_luts
 from repro.models.model import decode_step, init_cache, mixed_step, prefill
+from repro.serving import tracing
 from repro.serving.drafter import NgramDrafter
 from repro.serving.faults import (FaultInjector, InjectedFault,
                                   InjectedStepError)
@@ -170,7 +179,6 @@ class StageReport:
     num_prefill: int            # prefill-chunk rows this stage
     k_cold: int
     bandwidth_flop_fraction: float
-    wall_time: float
     # K+V bytes the attention paths stream this stage (all attention
     # layers). Dense: max_slots × max_len regardless of occupancy (+ chunk
     # slot-row gathers). Paged: live pages of the active decode slots plus
@@ -241,6 +249,14 @@ class ChainInfo:
             return "full"
         return "pure" if (self.src_nxt >= 0).all() else "nxt_only"
 
+    def wrap(self, fn, params):
+        """``fn`` behind the on-device token gather, and its leading
+        arguments: the gather's inputs take the place of the token
+        array."""
+        return _chain_fn(fn, self.mode), (params, self.prev_nxt,
+                                          self.prev_cn, self.src_nxt,
+                                          self.src_cn, self.fallback)
+
 
 @dataclass
 class StagePlan:
@@ -254,7 +270,6 @@ class StagePlan:
     decision: StageDecision
     k_cold: int
     splan: Optional[Any]
-    t0: float                       # wall clock at plan start
     snap: Tuple[int, int, int, int]  # (shed, expired, cancelled, retries)
     tnow: float = 0.0               # engine clock tokens are recorded at
     speculative: bool = False
@@ -281,7 +296,6 @@ class StageFuture:
     # per-stage robustness-counter deltas, frozen by ``_commit_critical`` so
     # the deferred report can't absorb the NEXT stage's window
     deltas: Tuple[int, int, int, int] = (0, 0, 0, 0)
-    t_dispatch: float = 0.0
     # speculative decoding (PR 9): per-stage draft/accept counts frozen at
     # the critical commit for the deferred StageReport
     spec_proposed: int = 0
@@ -501,11 +515,12 @@ class ServingEngine:
         # cheaper than diffing scheduler state.
         self._epoch = 0
         self._inflight: Optional[StageFuture] = None   # step_async() only
-        # host stage-gap accounting: wall time from a stage's result
-        # materialization to the NEXT stage's dispatch — the window the
-        # device sits idle waiting on the host. The async loop exists to
-        # drive this toward zero.
+        # host stage-gap accounting: from the end of a stage's
+        # ``engine.sync`` span to the end of the NEXT stage's last
+        # ``engine.launch`` — the window the device sits idle waiting on
+        # the host. The async loop exists to drive this toward zero.
         self._t_sync_done: Optional[float] = None
+        self._t_launched = 0.0
         self.host_gap_s = 0.0
         self.gap_stages = 0
         self.spec_hits = 0      # speculative plans dispatched as-is
@@ -746,6 +761,7 @@ class ServingEngine:
                 raise
             for victim in shed:
                 self._finish_abnormal(victim, "shed", tnow)
+            req.queued_at = time.monotonic()
             self._requests[req.rid] = req
             self._match_prefix(req)
             self._epoch += 1            # invalidates any speculative plan
@@ -909,6 +925,7 @@ class ServingEngine:
         else:
             pre.recompute_out(self.kv, victim)
         self.scheduler.resubmit_preempted(victim)
+        victim.queued_at = time.monotonic()
         # the replay can re-match whatever shared prefix pages survived the
         # eviction under their other owners (eviction may not change the
         # index, so force a fresh walk)
@@ -982,13 +999,22 @@ class ServingEngine:
         """Re-admit a migrated request: scatter its host-saved KV back into
         a fresh slot and resume decoding (no recompute)."""
         from repro.serving import preemption as pre
-        slot = self.kv.allocate()
+        slot = self._claim_slot(req)
         pre.restore_slot(self.kv, slot, req.saved_cache)
         req.saved_cache = None
         req.slot = slot
         self._slot_req[slot] = req
         self._tokens[slot] = req.output[-1]
         req.state = RequestState.DECODE
+
+    def _claim_slot(self, req: Request) -> int:
+        """A KV slot for ``req`` as it leaves the queue; its wait since it
+        entered the queue is one ``engine.queue`` span."""
+        slot = self.kv.allocate()
+        if req.queued_at is not None:
+            tracing.mark("engine.queue", req.queued_at, time.monotonic(),
+                         rid=req.rid)
+        return slot
 
     # ---------------------------------------------------------------- stages
     def _invoke(self, fn, *args):
@@ -997,19 +1023,31 @@ class ServingEngine:
         retry plus virtual backoff; ``max_retries`` consecutive failures
         raise :class:`InjectedStepError` and the whole stage aborts. Safe
         because step functions are pure — a retried attempt reads the same
-        cache state the failed one would have."""
-        if self.injector is None:
-            return fn(*args)
-        attempt = 0
-        while self.injector.step_error():
-            attempt += 1
-            self.retries += 1
-            self.fault_delay += self.injector.backoff(attempt)
-            if attempt >= self.injector.max_retries:
-                raise InjectedStepError(
-                    f"stage step failed {attempt} consecutive times "
-                    f"(max_retries={self.injector.max_retries})")
-        return fn(*args)
+        cache state the failed one would have. Every jitted stage call
+        passes through here, inside one ``engine.launch`` span."""
+        with tracing.span("engine.launch") as sp:
+            attempt = 0
+            while self.injector is not None and self.injector.step_error():
+                attempt += 1
+                self.retries += 1
+                self.fault_delay += self.injector.backoff(attempt)
+                if attempt >= self.injector.max_retries:
+                    raise InjectedStepError(
+                        f"stage step failed {attempt} consecutive times "
+                        f"(max_retries={self.injector.max_retries})")
+            out = fn(*args)
+        self._t_launched = sp.t1
+        return out
+
+    def _launch(self, fut: StageFuture, fn, args, outs) -> None:
+        """Invoke one staged step call and keep its outputs: each name of
+        ``outs`` is a :class:`StageFuture` field, except ``cache``, the KV
+        cache the call returns."""
+        for name, val in zip(outs, self._invoke(fn, *args), strict=True):
+            if name == "cache":
+                self.kv.cache = val
+            else:
+                setattr(fut, name, val)
 
     def _unique_page_bytes(self, slot_pages) -> int:
         """Streamed-KV bytes for a paged stage: UNIQUE pages across all the
@@ -1038,11 +1076,11 @@ class ServingEngine:
             buf.fill(0)
         return buf
 
-    def _dispatch_decode(self, fut: StageFuture) -> None:
-        """Dispatch half of a decoding-only stage (the dominant kind): host
-        KV growth, input staging and the jitted enqueue. Leaves the
-        next-token / router-count DEVICE arrays on ``fut`` without
-        materializing them."""
+    def _stage_decode(self, fut: StageFuture):
+        """Inputs of a decoding-only stage (the dominant kind): host KV
+        growth and input staging. Returns the step call for
+        :meth:`_launch`, which leaves the next-token / router-count DEVICE
+        arrays on ``fut`` without materializing them."""
         decision = fut.plan.decision
         k_cold = fut.plan.k_cold
         chain = fut.plan.chain
@@ -1087,16 +1125,12 @@ class ServingEngine:
             # C++ arg path converts them an order of magnitude cheaper
             # than explicit jnp.asarray device_puts
             if chain is not None:
-                fut.nxt, self.kv.cache, fut.counts = self._invoke(
-                    _chain_fn(fn, chain.mode), self.params, chain.prev_nxt,
-                    chain.prev_cn, chain.src_nxt, chain.src_cn,
-                    chain.fallback, self.kv.cache,
-                    lengths, bt, self._next_key())
+                fn, lead = chain.wrap(fn, self.params)
             else:
-                fut.nxt, self.kv.cache, fut.counts = self._invoke(
-                    fn, self.params, tokens, self.kv.cache,
-                    lengths, bt, self._next_key())
-            return
+                lead = (self.params, tokens)
+            return (fn, lead + (self.kv.cache, lengths, bt,
+                                self._next_key()),
+                    ("nxt", "cache", "counts"))
         # dense: runs over ALL slots — outputs of inactive slots are
         # discarded (and masked out of MoE routing), their cache is
         # overwritten on reuse, and their dead KV is streamed every stage.
@@ -1107,16 +1141,13 @@ class ServingEngine:
         fut.moe_caps = self._moe_caps(self.kv.max_slots, k_cold)
         fn = self._decode_fn(k_cold, *fut.moe_caps)
         if chain is not None:
-            fut.nxt, self.kv.cache, fut.counts = self._invoke(
-                _chain_fn(fn, chain.mode), self.params, chain.prev_nxt, chain.prev_cn,
-                chain.src_nxt, chain.src_cn, chain.fallback,
-                valid, self.kv.cache, self._next_key())
+            fn, lead = chain.wrap(fn, self.params)
         else:
             toks = self._staging("d_toks", (self.kv.max_slots, 1), np.int32)
             toks[:, 0] = self._tokens
-            fut.nxt, self.kv.cache, fut.counts = self._invoke(
-                fn, self.params, toks, valid, self.kv.cache,
-                self._next_key())
+            lead = (self.params, toks)
+        return (fn, lead + (valid, self.kv.cache, self._next_key()),
+                ("nxt", "cache", "counts"))
 
     def _row_live(self, r: Request) -> bool:
         """Commit guard: may this in-flight row's result be applied to
@@ -1159,18 +1190,18 @@ class ServingEngine:
             if emit:
                 fut.emitted.append((r.rid, tok))
 
-    def _dispatch_mixed(self, fut: StageFuture) -> None:
-        """Dispatch half of a unified mixed stage: first chunks claim their
-        slots (admission — unwound by ``_abort_stage`` on an injected
-        fault), inputs stage, and one jitted step is enqueued for decode
-        rows + chunk rows; the final chunk of a prompt samples its first
-        token at commit."""
+    def _stage_mixed(self, fut: StageFuture):
+        """Inputs of a unified mixed stage: first chunks claim their slots
+        (admission — unwound by ``_abort_stage`` on an injected fault) and
+        inputs stage for one jitted step over decode rows + chunk rows,
+        returned for :meth:`_launch`; the final chunk of a prompt samples
+        its first token at commit."""
         decision = fut.plan.decision
         k_cold = fut.plan.k_cold
         chunks = decision.chunks
         for c in chunks:                       # first chunk claims the slot
             if c.req.slot < 0:
-                s = self.kv.allocate()
+                s = self._claim_slot(c.req)
                 c.req.slot = s
                 self._slot_req[s] = c.req
                 if c.req.shared_pages:
@@ -1245,27 +1276,14 @@ class ServingEngine:
             fut.moe_caps = self._moe_caps(nb + nc_b * sc_b, k_cold)
             fn = self._mixed_fn(k_cold, *fut.moe_caps, nc_b, sc_b,
                                 nb, mp, mpc, spec)
+            # a chained stage never carries verify spans (_build_chain
+            # refuses them), so it never has the cn_all output
             if chain is not None:
-                # a chained stage never carries verify spans
-                # (_build_chain refuses them) — 4-tuple unpack is safe
-                fut.nxt, fut.cn, self.kv.cache, fut.counts = self._invoke(
-                    _chain_fn(fn, chain.mode), self.params, chain.prev_nxt,
-                    chain.prev_cn, chain.src_nxt, chain.src_cn,
-                    chain.fallback, lengths, bt, ctokens, starts,
-                    clens, bt_c, self.kv.cache, self._next_key())
-            elif spec:
-                (fut.nxt, fut.cn, fut.cn_all, self.kv.cache,
-                 fut.counts) = self._invoke(
-                    fn, self.params, dtokens, lengths,
-                    bt, ctokens, starts,
-                    clens, bt_c, self.kv.cache,
-                    self._next_key())
+                fn, lead = chain.wrap(fn, self.params)
             else:
-                fut.nxt, fut.cn, self.kv.cache, fut.counts = self._invoke(
-                    fn, self.params, dtokens, lengths,
-                    bt, ctokens, starts,
-                    clens, bt_c, self.kv.cache,
-                    self._next_key())
+                lead = (self.params, dtokens)
+            args = lead + (lengths, bt, ctokens, starts, clens, bt_c,
+                           self.kv.cache, self._next_key())
         else:
             cslots = self._staging("m_cslots", (nc_b,), np.int32)
             for i, c in enumerate(chunks):
@@ -1282,29 +1300,17 @@ class ServingEngine:
             fn = self._mixed_fn(k_cold, *fut.moe_caps, nc_b, sc_b,
                                 spec=spec)
             if chain is not None:
-                # chained stages never carry verify spans (see above)
-                fut.nxt, fut.cn, self.kv.cache, fut.counts = self._invoke(
-                    _chain_fn(fn, chain.mode), self.params, chain.prev_nxt,
-                    chain.prev_cn, chain.src_nxt, chain.src_cn,
-                    chain.fallback, valid, ctokens, cslots,
-                    starts, clens, self.kv.cache, self._next_key())
+                fn, lead = chain.wrap(fn, self.params)
             else:
                 dtokens = self._staging("m_dtoks",
                                         (self.kv.max_slots, 1), np.int32)
                 dtokens[:, 0] = self._tokens
-                if spec:
-                    (fut.nxt, fut.cn, fut.cn_all, self.kv.cache,
-                     fut.counts) = self._invoke(
-                        fn, self.params, dtokens, valid,
-                        ctokens, cslots,
-                        starts, clens, self.kv.cache,
-                        self._next_key())
-                else:
-                    fut.nxt, fut.cn, self.kv.cache, fut.counts = self._invoke(
-                        fn, self.params, dtokens, valid,
-                        ctokens, cslots,
-                        starts, clens, self.kv.cache,
-                        self._next_key())
+                lead = (self.params, dtokens)
+            args = lead + (valid, ctokens, cslots, starts, clens,
+                           self.kv.cache, self._next_key())
+        outs = (("nxt", "cn", "cn_all", "cache", "counts") if spec
+                else ("nxt", "cn", "cache", "counts"))
+        return fn, args, outs
 
     def _commit_mixed(self, fut: StageFuture, mat: Dict[str, Any],
                       tnow: float) -> None:
@@ -1421,11 +1427,11 @@ class ServingEngine:
         if dense_rw_slots:
             self.kv.rewind_dense(dense_rw_slots, dense_rw_lens)
 
-    def _dispatch_legacy_prefill(self, fut: StageFuture) -> None:
-        """Dispatch half of the monolithic whole-prompt prefill
-        (non-unified archs only): enqueue the prefill step into a fresh
-        local cache; slots are claimed and the cache scattered at commit
-        (pre-split behavior — nothing to unwind on an abort)."""
+    def _stage_legacy_prefill(self, fut: StageFuture):
+        """Inputs of the monolithic whole-prompt prefill (non-unified
+        archs only), for a step into a fresh local cache; slots are claimed
+        and the cache scattered at commit (pre-split behavior — nothing to
+        unwind on an abort)."""
         assert not self.paged
         decision = fut.plan.decision
         # whole-prompt spans; a recompute-preempted replay covers prompt +
@@ -1441,10 +1447,9 @@ class ServingEngine:
         for i, sq in enumerate(seqs):
             tokens[i, :len(sq)] = sq
             true_len[i] = len(sq)
-        fn = self._legacy_prefill_fn(n_b, l_b)
-        fut.legacy_nxt, fut.legacy_cache = self._invoke(
-            fn, self.params, tokens, true_len,
-            self._next_key())
+        return (self._legacy_prefill_fn(n_b, l_b),
+                (self.params, tokens, true_len, self._next_key()),
+                ("legacy_nxt", "legacy_cache"))
 
     def _commit_legacy_prefill(self, fut: StageFuture, mat: Dict[str, Any],
                                tnow: float) -> None:
@@ -1457,7 +1462,7 @@ class ServingEngine:
         if not live:
             fut.legacy_cache = None
             return
-        slots = [self.kv.allocate() for _ in live]
+        slots = [self._claim_slot(r) for _, r in live]
         take = jnp.asarray([i for i, _ in live], dtype=jnp.int32)
         local = [jax.tree_util.tree_map(lambda a: a[:, take], seg)
                  for seg in fut.legacy_cache]
@@ -1504,6 +1509,7 @@ class ServingEngine:
             # claimed yet — any queued-time pins stay valid and held
             r.state = RequestState.QUEUED
             r.prefill_target = None
+            r.queued_at = time.monotonic()
             requeue.append(r)
         requeue.extend(decision.restored)
         for r in reversed(requeue):
@@ -1534,6 +1540,7 @@ class ServingEngine:
         return len(errs)
 
     # ------------------------------------------------ plan / dispatch / commit
+    @tracing.traced("engine.plan.maintain")
     def _stage_maintenance(self, now: Optional[float] = None) -> float:
         """Pre-stage housekeeping, in the exact order of the pre-split
         engine: injected latency lands on the clock, the expiry sweep
@@ -1559,6 +1566,7 @@ class ServingEngine:
                     self._match_prefix(r)
         return tnow
 
+    @tracing.traced("engine.plan.admit")
     def _page_admission_cap(self) -> int:
         """Paged admission backpressure: walk the queue in admission order,
         accumulating each candidate's demand minus the prefix pages it
@@ -1593,7 +1601,8 @@ class ServingEngine:
             admit += 1
         return admit
 
-    def _finish_plan(self, decision: StageDecision, t0: float,
+    @tracing.traced("engine.plan.duplex")
+    def _finish_plan(self, decision: StageDecision,
                      snap: Tuple[int, int, int, int], tnow: float,
                      speculative: bool = False) -> StagePlan:
         """Wrap a scheduler decision into a :class:`StagePlan`: pick
@@ -1613,9 +1622,10 @@ class ServingEngine:
         splan = (core_plan_stage(self.cfg, mix, kv_quant=self.kv.kv_quant)
                  if mix.num_tokens else None)
         return StagePlan(decision=decision, k_cold=k_cold, splan=splan,
-                         t0=t0, snap=snap, tnow=tnow,
+                         snap=snap, tnow=tnow,
                          speculative=speculative, epoch=self._epoch)
 
+    @tracing.traced("engine.plan.draft")
     def _build_drafts(self) -> Optional[Dict[int, Tuple[int, List[int]]]]:
         """PR 9: host-side n-gram drafting for the next stage. For every
         decode-eligible row, ask the :class:`NgramDrafter` for up to
@@ -1660,6 +1670,7 @@ class ServingEngine:
             drafts[r.rid] = (start, toks)
         return drafts or None
 
+    @tracing.traced("engine.plan")
     def plan_stage(self, now: Optional[float] = None, *,
                    maintain: bool = True,
                    snap: Optional[Tuple[int, int, int, int]] = None
@@ -1671,7 +1682,6 @@ class ServingEngine:
         scheduler's span/admission walk, and the Op/B execution plan.
         Pure host work, no device sync. Returns None when no stage can be
         formed."""
-        t0 = time.monotonic()
         if snap is None:
             snap = (self.shed, self.expired, self.cancelled, self.retries)
         tnow = self._stage_maintenance(now) if maintain else self._now(now)
@@ -1679,11 +1689,13 @@ class ServingEngine:
         if self.paged:
             free = min(free, self._page_admission_cap())
         drafts = self._build_drafts() if self.drafter is not None else None
-        decision = self.scheduler.next_stage(free, drafts=drafts)
+        with tracing.span("engine.plan.schedule"):
+            decision = self.scheduler.next_stage(free, drafts=drafts)
         if decision is None:
             return None
-        return self._finish_plan(decision, t0, snap, tnow)
+        return self._finish_plan(decision, snap, tnow)
 
+    @tracing.traced("engine.dispatch")
     def dispatch_stage(self, plan: StagePlan) -> StageFuture:
         """Enqueue a planned stage on the device WITHOUT waiting for it:
         speculative plans activate their admissions first (the plan never
@@ -1699,13 +1711,15 @@ class ServingEngine:
         fut = StageFuture(plan=plan)
         decision = plan.decision
         if decision.chunks and self._unified:
-            self._dispatch_mixed(fut)
-        else:
-            if decision.decoding:
-                self._dispatch_decode(fut)
-            if decision.chunks:              # non-unified archs only
-                self._dispatch_legacy_prefill(fut)
-        fut.t_dispatch = time.monotonic()
+            calls = (self._stage_mixed,)
+        else:                                # legacy: non-unified archs only
+            calls = (((self._stage_decode,) if decision.decoding else ())
+                     + ((self._stage_legacy_prefill,) if decision.chunks
+                        else ()))
+        for stage_inputs in calls:
+            with tracing.span("engine.dispatch.inputs"):
+                call = stage_inputs(fut)
+            self._launch(fut, *call)
         if plan.chain is not None:
             # chained dispatch: enqueued BEFORE the in-flight stage's sync
             # point, while the device is still executing it — the idle
@@ -1717,7 +1731,7 @@ class ServingEngine:
             # host stage gap: the device-idle window between the previous
             # stage's materialization and this enqueue — what the async
             # loop exists to shrink
-            self.host_gap_s += max(fut.t_dispatch - self._t_sync_done, 0.0)
+            self.host_gap_s += max(self._t_launched - self._t_sync_done, 0.0)
             self.gap_stages += 1
             self._t_sync_done = None
         return fut
@@ -1728,17 +1742,19 @@ class ServingEngine:
         client submits/cancels and fleet polls never wait behind device
         compute."""
         mat: Dict[str, Any] = {}
-        if fut.nxt is not None:
-            mat["nxt"] = np.asarray(fut.nxt)
-        if fut.cn is not None:
-            mat["cn"] = np.asarray(fut.cn)
-        if fut.cn_all is not None:
-            mat["cn_all"] = np.asarray(fut.cn_all)
-        if fut.legacy_nxt is not None:
-            mat["legacy_nxt"] = np.asarray(fut.legacy_nxt)
-        self._t_sync_done = time.monotonic()
+        with tracing.span("engine.sync") as sp:
+            if fut.nxt is not None:
+                mat["nxt"] = np.asarray(fut.nxt)
+            if fut.cn is not None:
+                mat["cn"] = np.asarray(fut.cn)
+            if fut.cn_all is not None:
+                mat["cn_all"] = np.asarray(fut.cn_all)
+            if fut.legacy_nxt is not None:
+                mat["legacy_nxt"] = np.asarray(fut.legacy_nxt)
+        self._t_sync_done = sp.t1
         return mat
 
+    @tracing.traced("engine.commit")
     def _commit_critical(self, fut: StageFuture,
                          mat: Dict[str, Any]) -> None:
         """The durable half of a commit — everything the NEXT stage's
@@ -1777,6 +1793,7 @@ class ServingEngine:
                       self.cancelled - plan.snap[2],
                       self.retries - plan.snap[3])
 
+    @tracing.traced("engine.account")
     def _commit_deferred(self, fut: StageFuture) -> StageReport:
         """The accounting half of a commit: router-count EMA, the MoE
         streamed-bytes / padded-vs-live FLOP traffic model, the
@@ -1827,7 +1844,6 @@ class ServingEngine:
             num_prefill=len(decision.chunks), k_cold=k_cold,
             bandwidth_flop_fraction=(plan.splan.bandwidth_fraction()
                                      if plan.splan else 0.0),
-            wall_time=time.monotonic() - plan.t0,
             kv_bytes_streamed=int(fut.kv_bytes),
             moe_bytes_streamed=int(moe_bytes),
             moe_flops_live=int(moe_flops_live),
@@ -1856,8 +1872,7 @@ class ServingEngine:
             stage_index=self._stage_idx, is_mixed=decision.is_mixed,
             num_decode=len(decision.decoding),
             num_prefill=len(decision.chunks), k_cold=plan.k_cold,
-            bandwidth_flop_fraction=0.0,
-            wall_time=time.monotonic() - plan.t0, aborted=True,
+            bandwidth_flop_fraction=0.0, aborted=True,
             shed=self.shed - plan.snap[0],
             expired=self.expired - plan.snap[1],
             cancelled=self.cancelled - plan.snap[2],
@@ -1892,7 +1907,7 @@ class ServingEngine:
         commit) — and the stage reports ``aborted=True``. The lock is held
         across the whole stage, so concurrent submits/cancels/polls land
         between stages."""
-        with self._lock:
+        with self._lock, tracing.span("engine.step") as sp:
             plan = self.plan_stage(now)
             if plan is None:
                 return None
@@ -1900,10 +1915,14 @@ class ServingEngine:
                 fut = self.dispatch_stage(plan)
             except InjectedFault:
                 self._abort_stage(plan.decision)
-                return self._abort_report(plan)
-            return self.commit_stage(fut)
+                rep = self._abort_report(plan)
+            else:
+                rep = self.commit_stage(fut)
+            sp.stage = rep.stage_index
+            return rep
 
     # ------------------------------------------------- speculation (async)
+    @tracing.traced("engine.plan")
     def _plan_speculative(self, cur: StagePlan) -> Optional[StagePlan]:
         """Plan stage N+1 from the PROJECTED post-commit state of the
         in-flight stage N, touching no scheduler or request state.
@@ -1928,7 +1947,6 @@ class ServingEngine:
             self.spec_misses += 1
             self._reject_spec("rewind")
             return None
-        t0 = time.monotonic()
         pos: Dict[int, int] = {}
         done_rids = set()
         finished_prefill = set()     # in-flight final chunks: promote at
@@ -1988,13 +2006,14 @@ class ServingEngine:
             # current-state page cap — in-flight growth makes this an
             # approximation either way; validation re-checks the real cap
             free = min(free, self._page_admission_cap())
-        decision = self.scheduler.plan_stage(
-            free, prefilling=prefilling_proj, running=running_proj,
-            queue=queue_proj, pos=pos)
+        with tracing.span("engine.plan.schedule"):
+            decision = self.scheduler.plan_stage(
+                free, prefilling=prefilling_proj, running=running_proj,
+                queue=queue_proj, pos=pos)
         if decision is None:
             return None
         snap = (self.shed, self.expired, self.cancelled, self.retries)
-        return self._finish_plan(decision, t0, snap, self._now(None),
+        return self._finish_plan(decision, snap, self._now(None),
                                  speculative=True)
 
     def _build_chain(self, spec: StagePlan, fut: StageFuture
@@ -2138,6 +2157,7 @@ class ServingEngine:
             self.spec_miss_reasons.get(reason, 0) + 1
         return False
 
+    @tracing.traced("engine.turn")
     def _pipeline_turn(self, fut: StageFuture,
                        now: Optional[float] = None, dispatch: bool = True
                        ) -> Tuple[Optional[StageFuture],

@@ -72,6 +72,10 @@ class Request:
     # against, so queued heads are only re-matched when the index changed.
     shared_pages: Optional[List[int]] = None
     match_version: int = -1
+    # time.monotonic() when the request last entered the admission queue
+    # (submit, preemption, an aborted admission); its wait until it claims
+    # a KV slot is one ``engine.queue`` span
+    queued_at: Optional[float] = None
     # latency bookkeeping (T2FT / TBT / E2E, paper Fig. 2)
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None

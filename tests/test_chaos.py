@@ -223,7 +223,7 @@ def test_random_ops_property(seed):
 # ---- PR 9: verify spans under chaos ----------------------------------------
 def test_chaos_spec_spans_parity_and_seed_reproducibility(chaos_setup):
     """A speculative verify span rides its stage's SINGLE fault draw
-    (``_dispatch_mixed`` funnels the whole span through one ``_invoke``),
+    (``_stage_mixed`` stages the whole span for one ``_invoke``),
     so the injector schedule stays per-stage, not per-token: injected
     faults never change a committed token relative to the fault-free
     speculative run, and a fixed chaos seed replays fault-for-fault —
