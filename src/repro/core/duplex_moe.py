@@ -17,7 +17,11 @@ jit constraint: shapes must be static, so the *cold count* ``k_cold`` and the
 two capacities are compile-time constants chosen by the host-side planner
 (`core/partition.py`, one-stage-stale router statistics). The *membership*
 (which experts are hot) is dynamic: experts are ranked by token count inside
-the jitted function and weights are gathered by rank permutation.
+the jitted function. The Pallas kernels read the expert weights in place:
+they take the table row of each rank (``perm``, offset by ``layer * E`` in
+a segment's flattened (L*E, ., .) layer stack) as a scalar-prefetch
+operand, so no gathered, sliced, padded or per-layer copy of a table is
+made. The XLA path gathers one layer's tables into rank order.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, MoEConfig
+from repro.core.execution import EXPERT_TABLES, note_moe_lowering
 from repro.models.ffn import ffn_apply
 from repro.models.moe import RouterOut, route
 from repro.sharding.rules import logical_constraint
@@ -131,11 +136,29 @@ def duplex_dispatch(router: RouterOut, m: MoEConfig, T: int, *, k_cold: int,
                           k_cold, c_hot, c_cold)
 
 
-def _gather_weights(params, perm):
-    """Permute expert weights into rank order (one gather; the Pallas kernels
-    instead index experts via BlockSpec index maps without materializing)."""
-    keys = [k for k in ("wi_gate", "wi_up", "wi", "wo") if k in params]
-    return {k: jnp.take(params[k], perm, axis=0) for k in keys}
+def _gather_weights(params, perm, layer=None):
+    """One layer's expert weights permuted into rank order (one gather: the
+    XLA path, and the kernels where d_ff needs a pad). With ``layer`` the
+    tables are a segment's (L, E, ., .) stacks."""
+    keys = [k for k in EXPERT_TABLES if k in params]
+    return {k: jnp.take(params[k] if layer is None else params[k][layer],
+                        perm, axis=0) for k in keys}
+
+
+def _expert_rows(params, perm, layer, in_place: bool):
+    """(tables, ids): the weight tables the expert kernels read and the
+    table row of each rank. In place, the parameters themselves (a layer
+    stack flattened to (L*E, ., .), a bitcast) with ids ``layer*E + perm``;
+    else one layer's tables gathered into rank order, with ids the ranks."""
+    E = perm.shape[0]
+    if not in_place:
+        return (_gather_weights(params, perm, layer),
+                jnp.arange(E, dtype=jnp.int32))
+    keys = [k for k in EXPERT_TABLES if k in params]
+    if layer is None:
+        return {k: params[k] for k in keys}, perm
+    return ({k: params[k].reshape((-1,) + params[k].shape[2:])
+             for k in keys}, layer * E + perm)
 
 
 def _expert_ffn(w, x):
@@ -154,7 +177,7 @@ def duplex_moe_apply(params, cfg: ModelConfig, x, *, k_cold: int,
                      c_hot: Optional[int] = None, c_cold: Optional[int] = None,
                      use_kernels: bool = False, ragged: bool = False,
                      c_block: int = 256, return_stats: bool = False,
-                     token_valid=None):
+                     token_valid=None, layer=None):
     """Duplex MoE layer: hot experts through the grouped-GEMM path, cold
     experts through the gather-GEMV path. ``k_cold`` is static (planner).
 
@@ -170,6 +193,11 @@ def duplex_moe_apply(params, cfg: ModelConfig, x, *, k_cold: int,
     dispatch shard (per-shard slot buffers interleave live slots in the
     merged token dim); multi-shard dispatch falls back to the padded
     kernels.
+
+    With ``layer`` (traced int32) the expert tables in ``params`` are the
+    segment's whole (L, E, ., .) stacks. The kernels read them in place
+    wherever a kernel tile divides d_ff; otherwise (and on the XLA path)
+    the layer's tables are gathered into rank order first.
     """
     from repro.core.execution import shard_blocks
     from repro.models.moe import combine_slots, gather_slots
@@ -188,7 +216,13 @@ def duplex_moe_apply(params, cfg: ModelConfig, x, *, k_cold: int,
     n_cold = disp.k_cold * disp.c_cold          # per-shard cold slots
 
     x_slots = gather_slots(xb, disp.src_token)              # (n, n_slots, d)
-    w_perm = _gather_weights(params, disp.perm)
+    in_place = False
+    if use_kernels:
+        from repro.kernels.ops import expert_f_block
+        in_place = expert_f_block(m.d_ff_expert) is not None
+    tables, ids = _expert_rows(params, disp.perm, layer, in_place)
+    note_moe_lowering("inplace" if in_place else "gathered",
+                      1 if layer is None else params["wo"].shape[0])
     # live tokens per slot-buffer expert (rank order; dispatch fills each
     # expert's slots as a contiguous prefix) — scalar-prefetch operands of
     # the ragged kernels. Only exact for a single dispatch shard.
@@ -199,17 +233,17 @@ def duplex_moe_apply(params, cfg: ModelConfig, x, *, k_cold: int,
     if disp.k_cold > 0:
         x_cold = x_slots[:, :n_cold].reshape(n, disp.k_cold, disp.c_cold, -1)
         x_cold = x_cold.transpose(1, 0, 2, 3)   # (k_cold, n, Cc, d)
-        w_cold = {k: v[:disp.k_cold] for k, v in w_perm.items()}
         if use_kernels:
             from repro.kernels.ops import moe_gemv
             cold_counts = (jnp.minimum(counts_rank[:disp.k_cold], disp.c_cold)
                            if use_ragged else None)
-            y_cold = moe_gemv(w_cold, x_cold.reshape(disp.k_cold,
+            y_cold = moe_gemv(tables, x_cold.reshape(disp.k_cold,
                                                      n * disp.c_cold, -1),
-                              cold_counts)
+                              cold_counts, ids=ids[:disp.k_cold])
             y_cold = y_cold.reshape(disp.k_cold, n, disp.c_cold, -1)
         else:
-            y_cold = _expert_ffn(w_cold, x_cold)
+            y_cold = _expert_ffn({k: v[:disp.k_cold]
+                                  for k, v in tables.items()}, x_cold)
         y_cold = y_cold.transpose(1, 0, 2, 3).reshape(n, n_cold, -1)
     else:
         y_cold = jnp.zeros((n, 0, shape[-1]), x_flat.dtype)
@@ -220,23 +254,24 @@ def duplex_moe_apply(params, cfg: ModelConfig, x, *, k_cold: int,
         x_hot = x_hot.transpose(1, 0, 2, 3)
         x_hot = logical_constraint(x_hot,
                                    ("act_exp", "act_cap", None, "act_embed"))
-        w_hot = {k: v[disp.k_cold:] for k, v in w_perm.items()}
         if use_ragged:
             from repro.kernels.ops import ragged_moe_gemm
             hot_counts = jnp.minimum(counts_rank[disp.k_cold:], disp.c_hot)
-            y_hot = ragged_moe_gemm(w_hot,
+            y_hot = ragged_moe_gemm(tables,
                                     x_hot.reshape(E - disp.k_cold,
                                                   n * disp.c_hot, -1),
-                                    hot_counts, c_block=c_block)
+                                    hot_counts, ids=ids[disp.k_cold:],
+                                    c_block=c_block)
             y_hot = y_hot.reshape(E - disp.k_cold, n, disp.c_hot, -1)
         elif use_kernels:
             from repro.kernels.ops import moe_gemm
-            y_hot = moe_gemm(w_hot, x_hot.reshape(E - disp.k_cold,
-                                                  n * disp.c_hot, -1),
-                             c_block=c_block)
+            y_hot = moe_gemm(tables, x_hot.reshape(E - disp.k_cold,
+                                                   n * disp.c_hot, -1),
+                             ids=ids[disp.k_cold:], c_block=c_block)
             y_hot = y_hot.reshape(E - disp.k_cold, n, disp.c_hot, -1)
         else:
-            y_hot = _expert_ffn(w_hot, x_hot)
+            y_hot = _expert_ffn({k: v[disp.k_cold:]
+                                 for k, v in tables.items()}, x_hot)
         y_hot = logical_constraint(y_hot,
                                    ("act_exp", "act_cap", None, "act_embed"))
         y_hot = y_hot.transpose(1, 0, 2, 3).reshape(

@@ -15,6 +15,7 @@ the jitted stage function is traced under.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 from dataclasses import dataclass, replace
@@ -67,6 +68,34 @@ def current_plan() -> ExecutionPlan:
     return _PLAN.get()
 
 
+# the expert weight tables of an MoE block's params
+EXPERT_TABLES = ("wi_gate", "wi_up", "wi", "wo")
+
+_MOE_TALLY: contextvars.ContextVar = contextvars.ContextVar("moe_lowering",
+                                                            default=None)
+
+
+@contextlib.contextmanager
+def moe_lowering_tally():
+    """Count, while a step function is traced, how its MoE layers lower:
+    ``inplace`` (the expert kernels read the parameter tables where they
+    lie) or ``gathered`` (a copy of the layer's tables first: the XLA and
+    grouped paths, or a d_ff no kernel tile divides). Trace-time Python
+    only; the traced program carries nothing of it."""
+    tally = collections.Counter()
+    token = _MOE_TALLY.set(tally)
+    try:
+        yield tally
+    finally:
+        _MOE_TALLY.reset(token)
+
+
+def note_moe_lowering(kind: str, layers: int) -> None:
+    tally = _MOE_TALLY.get()
+    if tally is not None:
+        tally[kind] += layers
+
+
 def shard_blocks(x):
     """(B, S, d) -> (n, Tl, d) where each row is one (batch-block, seq-block)
     tile of the active plan's dispatch grid — aligned with the activation
@@ -97,10 +126,12 @@ def shard_blocks(x):
 
 
 def moe_execute(params, cfg: ModelConfig, x, *, return_stats: bool = False,
-                token_valid=None):
+                token_valid=None, layer=None):
     """Route the MoE layer through the path the active plan selects.
     ``token_valid`` (flat-token bool mask) excludes padded serving rows from
-    routing counts and expert capacity on either path."""
+    routing counts and expert capacity on either path. With ``layer`` (a
+    traced int32 scalar) the expert tables in ``params`` are the segment's
+    whole (L, E, ., .) stacks and ``layer`` picks the row of this layer."""
     plan = current_plan()
     # the ragged kernels live on the count-threaded duplex path, so a
     # duplex plan with k_cold == 0 still routes there when ragged is on
@@ -113,7 +144,11 @@ def moe_execute(params, cfg: ModelConfig, x, *, return_stats: bool = False,
                                 ragged=plan.moe_ragged,
                                 c_block=plan.moe_c_block,
                                 return_stats=return_stats,
-                                token_valid=token_valid)
+                                token_valid=token_valid, layer=layer)
     from repro.models.moe import moe_apply
+    if layer is not None:
+        keys = [k for k in EXPERT_TABLES if k in params]
+        note_moe_lowering("gathered", params[keys[0]].shape[0])
+        params = {**params, **{k: params[k][layer] for k in keys}}
     return moe_apply(params, cfg, x, capacity=plan.moe_capacity,
                      return_stats=return_stats, token_valid=token_valid)

@@ -6,9 +6,14 @@ gate/up/activation/down chain so the (C, f) hidden activation never leaves
 VMEM. Grid (E, nC, nF); the fp32 (bc, d) output accumulator is carried in
 VMEM across the f-block dimension and written once.
 
-Weight layout: (E, d, f)/(E, f, d) — the expert dim is the leading grid dim,
-so each expert's weights stream HBM->VMEM once per token-block pass
-(weights re-read nC times; hot-path C is chosen so nC is 1 or 2).
+Weight layout: tables of (R, d, f)/(R, f, d) rows, addressed through
+``ids`` (E,) int32, a scalar-prefetch operand: grid step e reads table row
+``ids[e]``. The tables are the parameters as they lie in HBM — a layer
+stack flattened to (L*E, d, f) with ``ids = layer*E + perm[...]`` — so no
+gather, slice or per-layer copy sits in front of the kernel. The expert dim
+is the leading grid dim, so each expert's weights stream HBM->VMEM once per
+token-block pass (weights re-read nC times; hot-path C is chosen so nC is 1
+or 2).
 
 Two variants:
 
@@ -38,8 +43,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _moe_gemm_kernel(x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref, *,
-                     nf: int):
+def _moe_gemm_kernel(ids_ref, x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref,
+                     *, nf: int):
+    del ids_ref                                      # read by the index maps
     fi = pl.program_id(2)
 
     @pl.when(fi == 0)
@@ -60,10 +66,16 @@ def _moe_gemm_kernel(x_ref, wg_ref, wu_ref, wo_ref, o_ref, acc_ref, *,
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def moe_gemm_kernel(w, x, *, c_block: int = 256, f_block: int = 256,
-                    interpret: bool = False):
-    """w: dict wi_gate/wi_up (E, d, f), wo (E, f, d); x: (E, C, d).
-    C % c_block == 0 and f % f_block == 0 (ops.py pads). -> (E, C, d)."""
+def _row_ids(ids, E: int):
+    return (jnp.arange(E, dtype=jnp.int32) if ids is None
+            else ids.astype(jnp.int32))
+
+
+def moe_gemm_kernel(w, x, ids=None, *, c_block: int = 256,
+                    f_block: int = 256, interpret: bool = False):
+    """w: dict wi_gate/wi_up (R, d, f), wo (R, f, d) tables; x: (E, C, d);
+    ids: (E,) int32 table row of each expert (default: row e). C % c_block
+    == 0 and f % f_block == 0 (ops.py pads). -> (E, C, d)."""
     E, C, d = x.shape
     f = w["wi_gate"].shape[2]
     c_block = min(c_block, C)
@@ -72,30 +84,38 @@ def moe_gemm_kernel(w, x, *, c_block: int = 256, f_block: int = 256,
     nc, nf = C // c_block, f // f_block
 
     kernel = functools.partial(_moe_gemm_kernel, nf=nf)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(E, nc, nf),
+        in_specs=[
+            pl.BlockSpec((1, c_block, d), lambda e, ci, fi, ids: (e, ci, 0)),
+            pl.BlockSpec((1, d, f_block),
+                         lambda e, ci, fi, ids: (ids[e], 0, fi)),
+            pl.BlockSpec((1, d, f_block),
+                         lambda e, ci, fi, ids: (ids[e], 0, fi)),
+            pl.BlockSpec((1, f_block, d),
+                         lambda e, ci, fi, ids: (ids[e], fi, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, c_block, d),
+                               lambda e, ci, fi, ids: (e, ci, 0)),
+        scratch_shapes=[pltpu.VMEM((c_block, d), jnp.float32)],
+    )
 
     return pl.pallas_call(
         kernel,
-        grid=(E, nc, nf),
-        in_specs=[
-            pl.BlockSpec((1, c_block, d), lambda e, ci, fi: (e, ci, 0)),
-            pl.BlockSpec((1, d, f_block), lambda e, ci, fi: (e, 0, fi)),
-            pl.BlockSpec((1, d, f_block), lambda e, ci, fi: (e, 0, fi)),
-            pl.BlockSpec((1, f_block, d), lambda e, ci, fi: (e, fi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c_block, d), lambda e, ci, fi: (e, ci, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((c_block, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w["wi_gate"], w["wi_up"], w["wo"])
+    )(_row_ids(ids, E), x, w["wi_gate"], w["wi_up"], w["wo"])
 
 
 # ---------------------------------------------------------------------------
 # Ragged (count-aware, scalar-prefetch) grouped GEMM
 # ---------------------------------------------------------------------------
 
-def _ragged_moe_gemm_kernel(nb_ref, lle_ref, x_ref, wg_ref, wu_ref, wo_ref,
+def _ragged_moe_gemm_kernel(nb_ref, lle_ids_ref, x_ref, wg_ref, wu_ref, wo_ref,
                             o_ref, acc_ref, *, nf: int):
     e = pl.program_id(0)
     ci = pl.program_id(1)
@@ -125,9 +145,9 @@ def _ragged_moe_gemm_kernel(nb_ref, lle_ref, x_ref, wg_ref, wu_ref, wo_ref,
 
 
 def _live_block_operands(counts, c_block: int, cap: int):
-    """(nb, lle) scalar-prefetch operands: per-expert live block counts and,
-    for empty experts, the nearest preceding live expert whose resident
-    blocks the index maps re-target (expert 0 if none)."""
+    """(nb, lle): per-expert live block counts and, for empty experts, the
+    nearest preceding live expert whose resident blocks the index maps
+    re-target (expert 0 if none)."""
     counts = jnp.minimum(counts.astype(jnp.int32), cap)
     nb = -(-counts // c_block)                       # ceil-div, 0 when empty
     E = counts.shape[0]
@@ -136,14 +156,19 @@ def _live_block_operands(counts, c_block: int, cap: int):
     return nb.astype(jnp.int32), lle.astype(jnp.int32)
 
 
-def ragged_moe_gemm_kernel(w, x, counts, *, c_block: int = 256,
+def ragged_moe_gemm_kernel(w, x, counts, ids=None, *, c_block: int = 256,
                            f_block: int = 256,
                            blocks_bound: int | None = None,
                            interpret: bool = False):
-    """w: dict wi_gate/wi_up (E, d, f), wo (E, f, d); x: (E, C, d) slot
-    buffers whose live tokens are a contiguous prefix of the C dim;
-    counts: (E,) int32 live tokens per expert. C % c_block == 0 and
-    f % f_block == 0 (ops.py pads). -> (E, C, d).
+    """w: dict wi_gate/wi_up (R, d, f), wo (R, f, d) tables; x: (E, C, d)
+    slot buffers whose live tokens are a contiguous prefix of the C dim;
+    counts: (E,) int32 live tokens per expert; ids: (E,) int32 table row of
+    each expert (default: row e). C % c_block == 0 and f % f_block == 0
+    (ops.py pads). -> (E, C, d).
+
+    Two scalar-prefetch operands: ``nb`` (live blocks per expert) and
+    ``concat(lle, ids)``. The clamp of dead steps stays in the slot
+    buffers' expert order; the weight maps then look the table row up.
 
     The token-block grid extent is ``blocks_bound`` (defaults to C/c_block;
     the serving engine trims the grid by sizing C itself to a bucketed
@@ -160,30 +185,31 @@ def ragged_moe_gemm_kernel(w, x, counts, *, c_block: int = 256,
     nbound = nc if blocks_bound is None else blocks_bound
     assert 1 <= nbound <= nc, (nbound, nc)
     nb, lle = _live_block_operands(counts, c_block, nbound * c_block)
+    lle_ids = jnp.concatenate([lle, _row_ids(ids, E)])
 
     kernel = functools.partial(_ragged_moe_gemm_kernel, nf=nf)
 
-    def x_map(e, ci, fi, nb, lle):
+    def x_map(e, ci, fi, nb, lle_ids):
         # clamp dead steps to the expert's last live block (empty expert:
         # the nearest preceding live expert's last live block) — same block
         # index as the previous step, so the pipeline elides the DMA.
         del fi
-        e_eff = jnp.where(nb[e] > 0, e, lle[e])
+        e_eff = jnp.where(nb[e] > 0, e, lle_ids[e])
         last = jnp.maximum(nb[e_eff] - 1, 0)
         return (e_eff, jnp.minimum(ci, last), 0)
 
-    def wi_map(e, ci, fi, nb, lle):
+    def wi_map(e, ci, fi, nb, lle_ids):
         # dead steps re-target the (e, nf-1) block left resident by the last
         # live step, so the 3 weight matrices are streamed once per *live*
         # token block only.
-        e_eff = jnp.where(nb[e] > 0, e, lle[e])
+        e_eff = jnp.where(nb[e] > 0, e, lle_ids[e])
         fi_eff = jnp.where(ci < nb[e], fi, nf - 1)
-        return (e_eff, 0, fi_eff)
+        return (lle_ids[E + e_eff], 0, fi_eff)
 
-    def wo_map(e, ci, fi, nb, lle):
-        e_eff = jnp.where(nb[e] > 0, e, lle[e])
+    def wo_map(e, ci, fi, nb, lle_ids):
+        e_eff = jnp.where(nb[e] > 0, e, lle_ids[e])
         fi_eff = jnp.where(ci < nb[e], fi, nf - 1)
-        return (e_eff, fi_eff, 0)
+        return (lle_ids[E + e_eff], fi_eff, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -205,7 +231,7 @@ def ragged_moe_gemm_kernel(w, x, counts, *, c_block: int = 256,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(nb, lle, x, w["wi_gate"], w["wi_up"], w["wo"])
+    )(nb, lle_ids, x, w["wi_gate"], w["wi_up"], w["wo"])
 
 
 def moe_gemm_traffic(counts, *, capacity: int, d_model: int, d_ff: int,

@@ -162,46 +162,64 @@ def chunked_prefill_attention(q, k_pages, v_pages, totals, starts,
 # MoE paths
 # ---------------------------------------------------------------------------
 
-def moe_gemm(w, x, *, c_block: int = 256, f_block: int = 256,
+def expert_f_block(f: int, f_block: int = 256) -> int | None:
+    """The d_ff tile of the expert kernels: ``f_block`` where it divides
+    d_ff (all of d_ff when narrower), else the widest lane-aligned (128)
+    tile below it that divides d_ff; None when none does, and the tables
+    must be padded to ``f_block``."""
+    if f <= f_block or f % f_block == 0:
+        return min(f, f_block)
+    for b in range(f_block - f_block % 128, 0, -128):
+        if f % b == 0:
+            return b
+    return None
+
+
+def _expert_tables(w, f_block: int):
+    """(tables, f tile): the tables as given where a tile divides d_ff,
+    else padded along d_ff (a copy of every row)."""
+    fb = expert_f_block(w["wi_gate"].shape[2], f_block)
+    if fb is not None:
+        return w, fb
+    return {"wi_gate": _pad_to(w["wi_gate"], f_block, 2),
+            "wi_up": _pad_to(w["wi_up"], f_block, 2),
+            "wo": _pad_to(w["wo"], f_block, 1)}, f_block
+
+
+def moe_gemm(w, x, *, ids=None, c_block: int = 256, f_block: int = 256,
              interpret: bool | None = None):
-    """Hot-expert grouped GEMM. x: (E, C, d) -> (E, C, d)."""
+    """Hot-expert grouped GEMM. x: (E, C, d) -> (E, C, d). ``w`` holds
+    (R, d, f)/(R, f, d) tables and ``ids`` (E,) the row of each expert
+    (default: row e)."""
     interpret = _interpret_default() if interpret is None else interpret
     E, C, d = x.shape
-    f = w["wi_gate"].shape[2]
     c_block = min(c_block, C)
-    f_block = min(f_block, f)
+    wp, f_block = _expert_tables(w, f_block)
     xp = _pad_to(x, c_block, 1)
-    wg = _pad_to(w["wi_gate"], f_block, 2)
-    wu = _pad_to(w["wi_up"], f_block, 2)
-    wo = _pad_to(w["wo"], f_block, 1)
-    out = moe_gemm_kernel({"wi_gate": wg, "wi_up": wu, "wo": wo}, xp,
-                          c_block=c_block, f_block=f_block,
+    out = moe_gemm_kernel(wp, xp, ids, c_block=c_block, f_block=f_block,
                           interpret=interpret)
     return out[:, :C]
 
 
-def ragged_moe_gemm(w, x, counts, *, c_block: int = 256, f_block: int = 256,
-                    blocks_bound: int | None = None,
+def ragged_moe_gemm(w, x, counts, *, ids=None, c_block: int = 256,
+                    f_block: int = 256, blocks_bound: int | None = None,
                     interpret: bool | None = None):
     """Count-aware hot-expert grouped GEMM. x: (E, C, d) slot buffers (live
     tokens a contiguous prefix of the C dim); counts: (E,) live tokens per
-    expert. Streamed weight bytes and FLOPs scale with live token blocks;
-    slots at or past each expert's count come back zeroed. -> (E, C, d)."""
+    expert; ``w``/``ids`` as in ``moe_gemm``. Streamed weight bytes and
+    FLOPs scale with live token blocks; slots at or past each expert's
+    count come back zeroed. -> (E, C, d)."""
     interpret = _interpret_default() if interpret is None else interpret
     E, C, d = x.shape
     c_block = min(c_block, C)
-    f_block = min(f_block, w["wi_gate"].shape[2])
+    wp, f_block = _expert_tables(w, f_block)
     xp = _pad_to(x, c_block, 1)
-    wg = _pad_to(w["wi_gate"], f_block, 2)
-    wu = _pad_to(w["wi_up"], f_block, 2)
-    wo = _pad_to(w["wo"], f_block, 1)
     if blocks_bound is not None:     # a bound past the buffer is a no-op
         blocks_bound = min(blocks_bound, xp.shape[1] // c_block)
     cap = C if blocks_bound is None else min(C, blocks_bound * c_block)
     counts = jnp.minimum(counts.astype(jnp.int32), cap)
-    out = ragged_moe_gemm_kernel({"wi_gate": wg, "wi_up": wu, "wo": wo}, xp,
-                                 counts, c_block=c_block, f_block=f_block,
-                                 blocks_bound=blocks_bound,
+    out = ragged_moe_gemm_kernel(wp, xp, counts, ids, c_block=c_block,
+                                 f_block=f_block, blocks_bound=blocks_bound,
                                  interpret=interpret)[:, :C]
     # dead blocks are never written by the kernel (their output DMAs are
     # elided along with their inputs) — mask so they read as zero.
@@ -209,24 +227,20 @@ def ragged_moe_gemm(w, x, counts, *, c_block: int = 256, f_block: int = 256,
     return jnp.where((slot < counts[:, None])[..., None], out, 0)
 
 
-def moe_gemv(w, x, counts=None, *, f_block: int = 256,
+def moe_gemv(w, x, counts=None, *, ids=None, f_block: int = 256,
              interpret: bool | None = None):
-    """Cold-expert gather GEMV. x: (Ec, Cc, d) -> (Ec, Cc, d). With
-    ``counts`` (Ec,) live tokens per expert, fully empty cold experts stream
-    no weights (scalar-prefetch DMA elision) and their rows come back
-    zeroed."""
+    """Cold-expert gather GEMV. x: (Ec, Cc, d) -> (Ec, Cc, d); ``w``/``ids``
+    as in ``moe_gemm``. With ``counts`` (Ec,) live tokens per expert, fully
+    empty cold experts stream no weights (scalar-prefetch DMA elision) and
+    their rows come back zeroed."""
     interpret = _interpret_default() if interpret is None else interpret
-    f = w["wi_gate"].shape[2]
-    f_block = min(f_block, f)
-    wg = _pad_to(w["wi_gate"], f_block, 2)
-    wu = _pad_to(w["wi_up"], f_block, 2)
-    wo = _pad_to(w["wo"], f_block, 1)
-    wp = {"wi_gate": wg, "wi_up": wu, "wo": wo}
+    wp, f_block = _expert_tables(w, f_block)
     if counts is None:
-        return moe_gemv_kernel(wp, x, f_block=f_block, interpret=interpret)
+        return moe_gemv_kernel(wp, x, ids, f_block=f_block,
+                               interpret=interpret)
     Ec, Cc, _ = x.shape
     counts = jnp.minimum(counts.astype(jnp.int32), Cc)
-    out = ragged_moe_gemv_kernel(wp, x, counts, f_block=f_block,
+    out = ragged_moe_gemv_kernel(wp, x, counts, ids, f_block=f_block,
                                  interpret=interpret)
     slot = jax.lax.broadcasted_iota(jnp.int32, (Ec, Cc), 1)
     return jnp.where((slot < counts[:, None])[..., None], out, 0)

@@ -315,6 +315,11 @@ def main(argv=None) -> int:
         print(f"[serve] MoE streamed bytes={moe_b/1e6:.2f}MB "
               f"({'ragged' if eng.moe_ragged else 'padded'} kernels); "
               f"live/padded FLOPs={live/max(padded, 1):.2f}")
+    if cfg.moe is not None:
+        lowered = eng.stats()
+        print(f"[serve] MoE layers over the traced stage programs: "
+              f"{lowered['moe_inplace_layers']} read the expert tables in "
+              f"place, {lowered['moe_gathered_layers']} gathered them")
     st = [r.stage_tokens for r in eng.reports]
     mode = (f"chunked@{args.prefill_chunk}" if args.prefill_chunk
             else "monolithic")

@@ -15,7 +15,7 @@ from repro.models.attention import (AttnCall, attention_decode_step,
                                     cross_attention_forward, cross_kv)
 from repro.models.ffn import ffn_apply, ffn_specs
 from repro.models.layers import rmsnorm, rmsnorm_specs
-from repro.core.execution import moe_execute
+from repro.core.execution import EXPERT_TABLES, moe_execute
 from repro.models.moe import moe_specs
 from repro.models.param import stack_specs
 from repro.models.ssm import (mamba_decode_step, mamba_forward,
@@ -156,14 +156,17 @@ def block_init_cache(cfg: ModelConfig, kind: LayerKind, batch: int,
 
 
 def block_decode_step(params, cfg: ModelConfig, kind: LayerKind, x, cache,
-                      attn_ctx=None, collect_counts: bool = False):
+                      attn_ctx=None, collect_counts: bool = False,
+                      layer=None):
     """Single-token decode. Returns (x, new_cache, moe_counts). ``attn_ctx``
     carries the stage's slot metadata ({"lengths", "block_tables"} for paged
     caches; optional "valid" (B,) live-row mask excluding padded/dead rows
     from MoE routing counts and capacity). ``moe_counts`` is the layer's
     per-expert routed-token counts ((E,) fp32) when ``collect_counts`` and
     the block has an MoE ffn, else None — the serving engine feeds the
-    actual counts (not a synthetic draw) back to the Duplex planner."""
+    actual counts (not a synthetic draw) back to the Duplex planner.
+    ``layer`` as in ``moe_execute``: the MoE expert tables in ``params``
+    are the segment's stacks."""
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
     valid = attn_ctx.get("valid") if attn_ctx else None
     counts = None
@@ -173,7 +176,7 @@ def block_decode_step(params, cfg: ModelConfig, kind: LayerKind, x, cache,
         if kind.ffn != MOE:
             return ffn_apply(params["ffn"], h_in)
         out, stats = moe_execute(params["ffn"], cfg, h_in, return_stats=True,
-                                 token_valid=valid)
+                                 token_valid=valid, layer=layer)
         if collect_counts:
             counts = stats.counts.astype(jnp.float32)
         return out
@@ -315,27 +318,52 @@ def segment_init_cache(cfg: ModelConfig, seg: Segment, batch: int,
         lambda a: jnp.broadcast_to(a[None], (seg.repeats,) + a.shape).copy(), one)
 
 
+def _split_expert_tables(params, seg: Segment):
+    """(scanned, tables): the serving scans slice ``scanned`` per layer,
+    while each MoE block's expert tables (``tables[i]``, {} for other
+    blocks) enter the scan body whole, as constants. The expert kernels
+    address (layer, expert) rows of the stacks in place, so no per-layer
+    slice of the tables is made."""
+    scanned, tables = [], []
+    for blk, kind in zip(params["blocks"], seg.pattern):
+        t = {}
+        if kind.ffn == MOE:
+            t = {k: v for k, v in blk["ffn"].items() if k in EXPERT_TABLES}
+            blk = {**blk, "ffn": {k: v for k, v in blk["ffn"].items()
+                                  if k not in EXPERT_TABLES}}
+        scanned.append(blk)
+        tables.append(t)
+    return {"blocks": tuple(scanned)}, tuple(tables)
+
+
+def _block_params(blk_params, tables, i: int):
+    blk = blk_params["blocks"][i]
+    return {**blk, "ffn": {**blk["ffn"], **tables[i]}} if tables[i] else blk
+
+
 def segment_decode_step(params, cfg: ModelConfig, seg: Segment, x, cache,
                         attn_ctx=None, collect_counts: bool = False):
     """With ``collect_counts`` also returns the segment's summed per-expert
     MoE routing counts ((E,) fp32, zeros if the segment has no MoE)."""
     E = cfg.moe.num_experts if (collect_counts and cfg.moe) else 0
+    scanned, tables = _split_expert_tables(params, seg)
 
     def body(x, inp):
-        blk_params, blk_cache = inp
+        blk_params, blk_cache, layer = inp
         new_caches = []
         counts = jnp.zeros((E,), jnp.float32)
         for i, kind in enumerate(seg.pattern):
-            x, nc, cnt = block_decode_step(blk_params["blocks"][i], cfg,
-                                           kind, x, blk_cache["blocks"][i],
-                                           attn_ctx=attn_ctx,
-                                           collect_counts=collect_counts)
+            x, nc, cnt = block_decode_step(
+                _block_params(blk_params, tables, i), cfg, kind, x,
+                blk_cache["blocks"][i], attn_ctx=attn_ctx,
+                collect_counts=collect_counts, layer=layer)
             new_caches.append(nc)
             if cnt is not None and E:
                 counts = counts + cnt
         return x, ({"blocks": tuple(new_caches)}, counts)
 
-    x, (new_cache, counts) = jax.lax.scan(body, x, (params, cache))
+    layers = jnp.arange(seg.repeats, dtype=jnp.int32)
+    x, (new_cache, counts) = jax.lax.scan(body, x, (scanned, cache, layers))
     if collect_counts:
         return x, new_cache, counts.sum(axis=0)
     return x, new_cache
@@ -351,7 +379,7 @@ def segment_decode_step(params, cfg: ModelConfig, seg: Segment, x, cache,
 
 def block_mixed_step(params, cfg: ModelConfig, kind: LayerKind, xd, xc,
                      cache, attn_ctx, chunk_ctx,
-                     collect_counts: bool = False):
+                     collect_counts: bool = False, layer=None):
     """One block of a unified mixed stage.
 
     xd: (Bd, 1, d) decode rows; xc: (Bc, Sc, d) prefill-chunk rows. The
@@ -359,7 +387,8 @@ def block_mixed_step(params, cfg: ModelConfig, kind: LayerKind, xd, xc,
     into the same cache (disjoint slots; on the dense layout the decode
     half's speculative write into a mid-prefill slot is overwritten by that
     slot's chunk, which starts exactly at its length). Full self-attention
-    mixers only. Returns (xd, xc, new_cache, moe_counts)."""
+    mixers only. ``layer`` as in ``block_decode_step``. Returns (xd, xc,
+    new_cache, moe_counts)."""
     from repro.models.attention import (attention_chunk_step,
                                         attention_decode_step,
                                         paged_attention_chunk_step,
@@ -406,7 +435,7 @@ def block_mixed_step(params, cfg: ModelConfig, kind: LayerKind, xd, xc,
                        < chunk_ctx["chunk_lens"][:, None].astype(jnp.int32))
         valid = jnp.concatenate([dec_valid, chunk_valid.reshape(-1)])
         y, stats = moe_execute(params["ffn"], cfg, flat, return_stats=True,
-                               token_valid=valid)
+                               token_valid=valid, layer=layer)
         if collect_counts:
             counts = stats.counts.astype(jnp.float32)
     else:
@@ -422,24 +451,26 @@ def segment_mixed_step(params, cfg: ModelConfig, seg: Segment, xd, xc,
     """Scan the segment's stacked super-blocks over both row groups.
     Returns (xd, xc, new_cache, counts) — counts summed over layers."""
     E = cfg.moe.num_experts if (collect_counts and cfg.moe) else 0
+    scanned, tables = _split_expert_tables(params, seg)
 
     def body(carry, inp):
         xd, xc = carry
-        blk_params, blk_cache = inp
+        blk_params, blk_cache, layer = inp
         new_caches = []
         counts = jnp.zeros((E,), jnp.float32)
         for i, kind in enumerate(seg.pattern):
             xd, xc, nc, cnt = block_mixed_step(
-                blk_params["blocks"][i], cfg, kind, xd, xc,
+                _block_params(blk_params, tables, i), cfg, kind, xd, xc,
                 blk_cache["blocks"][i], attn_ctx, chunk_ctx,
-                collect_counts=collect_counts)
+                collect_counts=collect_counts, layer=layer)
             new_caches.append(nc)
             if cnt is not None and E:
                 counts = counts + cnt
         return (xd, xc), ({"blocks": tuple(new_caches)}, counts)
 
+    layers = jnp.arange(seg.repeats, dtype=jnp.int32)
     (xd, xc), (new_cache, counts) = jax.lax.scan(body, (xd, xc),
-                                                 (params, cache))
+                                                 (scanned, cache, layers))
     return xd, xc, new_cache, counts.sum(axis=0)
 
 

@@ -86,6 +86,7 @@ request's entry into the queue to its KV slot.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -99,7 +100,8 @@ import numpy as np
 from repro.configs.base import ATTN, ATTN_LOCAL, MAMBA, MOE, ModelConfig
 from repro.core.costmodel import DUPLEX
 from repro.core.dispatch import plan_stage as core_plan_stage
-from repro.core.execution import ExecutionPlan, execution_plan
+from repro.core.execution import (ExecutionPlan, execution_plan,
+                                  moe_lowering_tally)
 from repro.core.partition import DuplexPlanner, build_luts
 from repro.models.model import decode_step, init_cache, mixed_step, prefill
 from repro.serving import tracing
@@ -493,6 +495,9 @@ class ServingEngine:
         self._decode_fns: Dict[Tuple, callable] = {}
         self._paged_decode_fns: Dict[Tuple, callable] = {}
         self._mixed_fns: Dict[Tuple, callable] = {}
+        # per stage program: how its MoE layers lowered when it was traced
+        # (``moe_lowering_tally``), summed by ``stats()``
+        self._moe_lowered: Dict[Tuple, Any] = {}
         self._legacy_prefill_fns: Dict[Tuple[int, int], callable] = {}
         # paged jit keys: (batch bucket, live-page bucket) — powers of two
         # so steady-state continuous batching never recompiles.
@@ -601,15 +606,24 @@ class ServingEngine:
         against ``dataclasses.replace(plan, use_kernels=False)``."""
         return self._moe_plan(k_cold, *self._moe_caps(n_tokens, k_cold))
 
+    @contextlib.contextmanager
+    def _tracing_stage(self, plan: ExecutionPlan, key: Tuple):
+        """The context a stage function's body is traced in: its plan, and
+        the tally of how its MoE layers lower, kept under ``key``."""
+        with execution_plan(plan), moe_lowering_tally() as tally:
+            yield
+        self._moe_lowered[key] = tally
+
     def _decode_fn(self, k_cold: int, c_hot: int, c_cold: int, c_block: int):
         key = (k_cold, c_hot, c_cold)
         if key not in self._decode_fns:
             cfg = self.cfg
             plan = self._moe_plan(k_cold, c_hot, c_cold, c_block)
+            stage = ("decode",) + key
 
             @jax.jit
             def fn(params, tokens, valid, cache, key):
-                with execution_plan(plan):
+                with self._tracing_stage(plan, stage):
                     logits, new_cache, counts = decode_step(
                         params, cfg, tokens, cache,
                         attn_ctx={"valid": valid}, return_moe_counts=True)
@@ -632,7 +646,7 @@ class ServingEngine:
 
             @jax.jit
             def fn(params, tokens, cache, lengths, block_tables, key_):
-                with execution_plan(plan):
+                with self._tracing_stage(plan, ("paged_decode",) + key):
                     logits, new_cache, counts = decode_step(
                         params, cfg, tokens, cache,
                         attn_ctx={"lengths": lengths,
@@ -667,7 +681,7 @@ class ServingEngine:
                 @jax.jit
                 def fn(params, dec_tokens, dec_lengths, dec_bt, chunk_tokens,
                        starts, clens, chunk_bt, cache, key_):
-                    with execution_plan(plan):
+                    with self._tracing_stage(plan, ("mixed",) + key):
                         out = mixed_step(
                             params, cfg, dec_tokens, chunk_tokens, cache,
                             attn_ctx={"lengths": dec_lengths,
@@ -688,7 +702,7 @@ class ServingEngine:
                 @jax.jit
                 def fn(params, dec_tokens, dec_valid, chunk_tokens, slots,
                        starts, clens, cache, key_):
-                    with execution_plan(plan):
+                    with self._tracing_stage(plan, ("mixed",) + key):
                         out = mixed_step(
                             params, cfg, dec_tokens, chunk_tokens, cache,
                             attn_ctx={"valid": dec_valid},
@@ -2443,6 +2457,13 @@ class ServingEngine:
                    "host_gap_s": self.host_gap_s,
                    "gap_stages": self.gap_stages,
                    "aging_promotions": self.scheduler.aging_promotions,
+                   # MoE layers of the traced stage programs whose expert
+                   # kernels read the weight tables in place / that
+                   # gathered a copy first (XLA path, padded d_ff)
+                   "moe_inplace_layers": sum(
+                       t["inplace"] for t in self._moe_lowered.values()),
+                   "moe_gathered_layers": sum(
+                       t["gathered"] for t in self._moe_lowered.values()),
                    "kv": self.kv.stats()}
             out["delta"] = {k: out[k] - self._stats_base.get(k, 0)
                             for k in self.STATS_DELTA_KEYS}

@@ -133,6 +133,56 @@ def test_ragged_gemv_empty_expert_patterns(counts):
 
 
 # ---------------------------------------------------------------------------
+# in-place reads: the kernels address (layer, expert) rows of a stacked table
+# ---------------------------------------------------------------------------
+
+def _stacked_case(seed, L, E, C, d, f):
+    """Tables of L layers of E experts flattened to (L*E, ., .), a slot
+    buffer of E experts in rank order and a random rank permutation."""
+    rng = np.random.default_rng(seed)
+    w = {"wi_gate": rng.standard_normal((L * E, d, f)) * 0.1,
+         "wi_up": rng.standard_normal((L * E, d, f)) * 0.1,
+         "wo": rng.standard_normal((L * E, f, d)) * 0.1}
+    w = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
+    x = jnp.asarray(rng.standard_normal((E, C, d)), jnp.float32)
+    return w, x, rng.permutation(E).astype(np.int32)
+
+
+@pytest.mark.parametrize("layer,f_block", [(1, 256), (2, 128)])
+@pytest.mark.parametrize("kernel", ["ragged_gemm", "ragged_gemv",
+                                    "padded_gemm", "padded_gemv"])
+def test_kernels_read_stacked_table_rows(kernel, layer, f_block):
+    """Each expert kernel reads its weights from rows ``layer*E + perm`` of
+    a stacked (L*E, d, f) table, f = 384 (no 256-wide tile divides it:
+    three of 128, no pad), and matches the reference on
+    explicitly permuted weights. The id range (the hot ranks) starts with
+    an empty expert and has one inside."""
+    L, E, C, d, f, k_cold = 3, 8, 16, 32, 384, 2
+    assert ops.expert_f_block(f, f_block) == 128
+    w, x_all, perm = _stacked_case(10 + layer, L, E, C, d, f)
+    rows = layer * E + perm[k_cold:]
+    x = x_all[k_cold:]
+    counts = jnp.asarray([0, 16, 5, 0, 9, 1], jnp.int32)
+    w_perm = {k: v[rows] for k, v in w.items()}
+    ids = jnp.asarray(rows)
+    if kernel == "ragged_gemm":
+        out = ops.ragged_moe_gemm(w, x, counts, ids=ids, c_block=8,
+                                  f_block=f_block, interpret=True)
+    elif kernel == "ragged_gemv":
+        out = ops.moe_gemv(w, x, counts, ids=ids, f_block=f_block,
+                           interpret=True)
+    elif kernel == "padded_gemm":
+        out = ops.moe_gemm(w, x, ids=ids, c_block=8, f_block=f_block,
+                           interpret=True)
+    else:
+        out = ops.moe_gemv(w, x, ids=ids, f_block=f_block, interpret=True)
+    exp = (ref.ragged_moe_ffn_ref(w_perm, x, counts)
+           if kernel.startswith("ragged") else ref.moe_ffn_ref(w_perm, x))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
 # duplex layer with count threading
 # ---------------------------------------------------------------------------
 

@@ -10,6 +10,9 @@ token block. The ``gqa*`` cases take grouped-query attention on 8 kv heads
 x 128, where a 256-token chunk holds chunk x (query heads per kv head) query
 rows per kv head: Command R's 64 query heads in bf16, Qwen3-8B's 32 in int8
 (the int8 kernel steps over 8 kv heads at once, so its VMEM grows 8-fold).
+The ``stacked*`` cases take DeepSeek-MoE-16B's expert width (1408, eleven
+128-wide tiles, no pad) and read a 5-layer stack of its 64-expert tables in
+place through row ids, as a served MoE layer does.
 """
 import os
 
@@ -67,6 +70,15 @@ def _experts(n):
             "wo": ((n, D_FF, D_MODEL), BF16)}
 
 
+STACK, DS_FF = 5 * EXPERTS, 1408
+
+
+def _stacked():
+    return {"wi_gate": ((STACK, D_MODEL, DS_FF), BF16),
+            "wi_up": ((STACK, D_MODEL, DS_FF), BF16),
+            "wo": ((STACK, DS_FF, D_MODEL), BF16)}
+
+
 def _scaled(fn):
     """Call ``fn`` with the int8 scale pools (trailing) as keywords."""
     return lambda *a: fn(*a[:-2], k_scales=a[-2], v_scales=a[-1])
@@ -116,6 +128,15 @@ CASES = {
     "ragged_moe_gemv": (
         lambda w, x, c: ops.moe_gemv(w, x, c, interpret=False),
         [_experts(COLD), ((COLD, C_COLD, D_MODEL), BF16), ((COLD,), I32)]),
+    "stacked_ragged_moe_gemm": (
+        lambda w, x, c, ids: ops.ragged_moe_gemm(
+            w, x, c, ids=ids, c_block=C_BLOCK, interpret=False),
+        [_stacked(), ((EXPERTS - COLD, C_HOT, D_MODEL), BF16),
+         ((EXPERTS - COLD,), I32), ((EXPERTS - COLD,), I32)]),
+    "stacked_ragged_moe_gemv": (
+        lambda w, x, c, ids: ops.moe_gemv(w, x, c, ids=ids, interpret=False),
+        [_stacked(), ((COLD, C_COLD, D_MODEL), BF16), ((COLD,), I32),
+         ((COLD,), I32)]),
 }
 
 
